@@ -7,11 +7,11 @@ Public API surface:
     repro.core      — the paper's contribution: TNN columns/layers, STDP, WTA,
                       and the macro-level PPA hardware model.
     repro.kernels   — Pallas TPU kernels for the TNN hot loops; the
-                      ``impl="pallas"`` production backend (Mosaic on TPU,
-                      bit-exact interpret fallback on CPU — DESIGN.md §8).
+                      ``impl="pallas"`` backend (Mosaic on TPU, the
+                      bit-exact interpreter on CPU — DESIGN.md §8).
     repro.models    — LM-family architecture substrate (10 assigned archs).
     repro.configs   — named architecture configs (``get_config(name)``).
-    repro.sharding  — mesh partitioning rules + version-portable shard_map.
+    repro.sharding  — mesh partitioning rules + the one shard_map entry.
     repro.train     — optimizers, train-step builder, trainer loop.
     repro.serve     — KV-cache LM engine and the slot-batched TNNEngine.
     repro.launch    — production mesh, dry-run, train/serve drivers.
@@ -26,7 +26,7 @@ Usage — run the paper's 2-layer prototype through the fused kernel path::
     params = init_network(jax.random.PRNGKey(0), cfg)
     z = network_forward(encode_images(images, cfg), params, cfg)[-1]
 
-The raw kernel entry points (padding + fallback handled for you) live in
+The raw kernel entry points (padding + interpret flag handled for you) live in
 ``repro.kernels``: ``column_forward``, ``wta``, ``stdp_update``, and the
 layer-level ``layer_forward_fused`` / ``layer_stdp_fused`` — see
 ``repro/kernels/ops.py`` for the padding semantics and a full example.

@@ -45,8 +45,8 @@ class NetworkConfig:
     # Bit-packed kernel IO for the fused wave executor (DESIGN.md §14):
     # spike volleys cross the pallas_call boundary as uint8 and weights as
     # int8, widening to i32 only inside the kernel accumulator. False keeps
-    # the i32-at-the-boundary layout (the known-safe Mosaic tiling) — the
-    # two are bit-exact, so the flag is a pure bytes/performance knob and is
+    # the i32-at-the-boundary layout — the two are bit-exact and both
+    # compile for a v5e, so the flag is a pure bytes/performance knob and is
     # deliberately excluded from the checkpoint config fingerprint.
     packed: bool = True
 

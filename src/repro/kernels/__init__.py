@@ -1,7 +1,7 @@
 # Pallas TPU kernels for the paper's compute hot spots (the column datapath
 # the custom macros implement in silicon): fused RNL-accumulate+threshold
 # forward, WTA inhibition, and the fused STDP update. ops.py wraps them with
-# padding + CPU interpret fallback; padding.py owns the launch geometry
+# padding + the per-backend interpret flag; padding.py owns the launch geometry
 # (PadPlan) and the network-level fused-wave plan (NetworkPlan); ref.py
 # holds the pure-jnp oracles. The layer-level entry points
 # (layer_forward_fused / layer_stdp_fused) are the production path selected

@@ -10,17 +10,17 @@ here make them safe for arbitrary shapes and both execution targets:
   uniforms=1.0) live in one place — :class:`repro.kernels.padding.PadPlan` —
   instead of being recomputed ad hoc in every wrapper.
 
-* **``interpret`` auto-fallback** (DESIGN.md §8). Every wrapper takes
-  ``interpret: bool | None``. ``None`` (the default) resolves to
-  ``jax.default_backend() != "tpu"``: on a real TPU the kernels compile via
-  Mosaic; everywhere else (the CPU-only CI container, laptops) Pallas runs
-  the kernel bodies through its interpreter, which is slow but bit-exact —
-  the same tests and the same call sites work on both targets unchanged.
+* **``interpret`` by backend** (DESIGN.md §8). Every wrapper takes
+  ``interpret: bool | None``. ``None`` (the default) resolves from
+  ``jax.default_backend()``: Mosaic on ``tpu``, the (slow but bit-exact)
+  Pallas interpreter on ``cpu`` — the test backend — and an error on any
+  other backend, so no run silently falls back to the interpreter.
 
 Layer-level entry points (:func:`layer_forward_fused`,
-:func:`layer_stdp_fused`) pad ONCE for the whole ``(B, n_cols, p)`` layer
-and then ``vmap`` the raw kernel over the column axis, so the pad/slice pair
-does not replicate per column inside the vmapped trace.
+:func:`layer_stdp_fused`) pad ONCE for the whole ``(B, n_cols, p)`` layer,
+go column-major, and launch ONE kernel whose leading grid dimension is the
+column axis — the same layout the fused wave uses. The single-column
+wrappers are the ``C = 1`` case of the same kernels.
 
 Usage — fused forward + learning for one layer (CPU or TPU)::
 
@@ -43,8 +43,6 @@ whole-network single-launch wave executor (``impl="fused"``) lives in
 :mod:`repro.kernels.tnn_wave` (DESIGN.md §10).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -74,10 +72,10 @@ def column_forward(
     x = plan.pad_spikes(x, T, p_axis=1)
     w = plan.pad_weights(w)
     z = column_forward_pallas(
-        x, w, theta=theta, T=T, wta=wta,
+        x[None], w[None], theta=theta, T=T, wta=wta,
         block_b=plan.block_b, block_p=plan.block_p, interpret=plan.interpret,
     )
-    return z[:B, :q]
+    return z[0, :B, :q]
 
 
 def wta(z: jax.Array, *, T: int = 8, block_b: int = 128, interpret: bool | None = None) -> jax.Array:
@@ -120,13 +118,13 @@ def stdp_update(
     u_up = plan.pad_uniforms(u_up, p_axis=1)
     u_dn = plan.pad_uniforms(u_dn, p_axis=1)
     res = stdp_update_pallas(
-        w, x, z, u_up, u_dn,
+        w[None], x[None], z[None], u_up[None], u_dn[None],
         T=T, w_max=w_max, table=tuple(table),
         mu_capture=mu_capture, mu_backoff=mu_backoff, mu_search=mu_search,
         block_p=plan.block_p, block_b=plan.block_b, interpret=plan.interpret,
         out=out,
     )
-    return res[:p]
+    return res[0, :p]
 
 
 def layer_forward_fused(
@@ -143,21 +141,20 @@ def layer_forward_fused(
     """Whole-layer fused forward+WTA: x (B, C, p), w (C, p, q) -> (B, C, q) i32.
 
     Pads the batch/synapse axes once for the whole layer (see the module
-    docstring for the no-op encodings), then vmaps the raw Pallas call over
-    the column axis — the layer's spatial replication (Fig. 1) becomes a
-    leading grid dimension of one kernel launch.
+    docstring for the no-op encodings) and goes column-major — the layer's
+    spatial replication (Fig. 1) becomes the leading grid dimension of one
+    kernel launch.
     """
     B, _, p = x.shape
     plan = PadPlan.make(B, p, block_b=block_b, block_p=block_p,
                         interpret=interpret)
-    x = plan.pad_spikes(x, T, p_axis=2)
+    x = plan.pad_spikes(x, T, p_axis=2).transpose(1, 0, 2)
     w = plan.pad_weights(w, p_axis=1)
-    f = functools.partial(
-        column_forward_pallas, theta=theta, T=T, wta=wta,
+    z = column_forward_pallas(
+        x, w, theta=theta, T=T, wta=wta,
         block_b=plan.block_b, block_p=plan.block_p, interpret=plan.interpret,
     )
-    z = jax.vmap(f, in_axes=(1, 0), out_axes=1)(x, w)
-    return z[:B]
+    return z.transpose(1, 0, 2)[:B]
 
 
 def layer_stdp_fused(
@@ -193,17 +190,16 @@ def layer_stdp_fused(
     B, _, p = x.shape
     plan = PadPlan.make(B, p, block_b=block_b, block_p=block_p,
                         interpret=interpret)
-    x = plan.pad_spikes(x, T, p_axis=2)
-    z = plan.pad_spikes(z, T)
+    x = plan.pad_spikes(x, T, p_axis=2).transpose(1, 0, 2)
+    z = plan.pad_spikes(z, T).transpose(1, 0, 2)
     w = plan.pad_weights(w, p_axis=1)
     u_up = plan.pad_uniforms(u_up, b_axis=1, p_axis=2)
     u_dn = plan.pad_uniforms(u_dn, b_axis=1, p_axis=2)
-    f = functools.partial(
-        stdp_update_pallas,
+    res = stdp_update_pallas(
+        w, x, z, u_up, u_dn,
         T=T, w_max=w_max, table=tuple(table),
         mu_capture=mu_capture, mu_backoff=mu_backoff, mu_search=mu_search,
         block_p=plan.block_p, block_b=plan.block_b, interpret=plan.interpret,
         out=out,
     )
-    res = jax.vmap(f, in_axes=(0, 1, 1, 0, 0))(w, x, z, u_up, u_dn)
     return res[:, :p]

@@ -32,8 +32,21 @@ import jax
 import jax.numpy as jnp
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """The Pallas ``interpret`` flag for this process: an explicit value
+    wins; ``None`` picks Mosaic on ``tpu`` and the bit-exact interpreter on
+    ``cpu`` (the test backend). Any other backend raises — a kernel never
+    falls back to the interpreter where a device was expected."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"no Pallas lowering for backend {backend!r}: the TNN kernels run "
+        f"via Mosaic on 'tpu' or in the interpreter on 'cpu'")
 
 
 def pad_to(n: int, m: int) -> int:
@@ -75,15 +88,13 @@ class PadPlan:
         interpret: Optional[bool] = None,
     ) -> "PadPlan":
         """Clamp block sizes to the aligned problem extents, compute the
-        padded extents, and resolve the interpret auto-fallback: ``None``
-        resolves to ``jax.default_backend() != "tpu"`` — Mosaic on a real
-        TPU, the (slow but bit-exact) interpreter everywhere else
-        (DESIGN.md §6, §8). ``p_align`` widens the synapse-axis alignment
+        padded extents, and resolve ``interpret`` by backend
+        (:func:`resolve_interpret`: Mosaic on ``tpu``, the bit-exact
+        interpreter on ``cpu``, an error elsewhere — DESIGN.md §6, §8). ``p_align`` widens the synapse-axis alignment
         above the tiling-minimum 8 — the autotuner's p1-pad knob
         (DESIGN.md §14): a larger alignment trades pad rows (all no-op
         encoded) for rounder VMEM tiles."""
-        if interpret is None:
-            interpret = not _on_tpu()
+        interpret = resolve_interpret(interpret)
         block_b = min(block_b, pad_to(b, 8))
         if p is None:
             p = block_p = pp = 0

@@ -8,10 +8,10 @@ over the <=8 table entries, the vector analogue of the 8-to-1 GDI mux),
 counter). Random uniforms are passed in explicitly so the kernel is a
 deterministic function checked exactly against ref.stdp_ref.
 
-Grid: (synapse tiles, batch tiles). The (Pt, q) inc/dec counters accumulate
-across batch tiles in VMEM scratch; the final batch tile applies the
-saturating update. Blocks: x (Bt, Pt), z (Bt, q), u (Bt, Pt, q) f32,
-w (Pt, q) i32.
+Grid: (columns, synapse tiles, batch tiles). The (Pt, q) inc/dec counters
+accumulate across batch tiles in VMEM scratch; the final batch tile applies
+the saturating update. Blocks (column-major): x (1, Bt, Pt), z (1, Bt, q),
+u (1, Bt, Pt, q) f32, w (1, Pt, q) i32.
 """
 from __future__ import annotations
 
@@ -76,17 +76,17 @@ def _stdp_kernel(
     n_b_tiles: int,
     out: str,
 ):
-    bt_idx = pl.program_id(1)
+    bt_idx = pl.program_id(2)
 
     @pl.when(bt_idx == 0)
     def _init():
         net_ref[...] = jnp.zeros_like(net_ref)
 
-    w = w_ref[...].astype(jnp.int32)  # (Pt, q)
-    x = x_ref[...].astype(jnp.int32)  # (Bt, Pt)
-    z = z_ref[...].astype(jnp.int32)  # (Bt, q)
+    w = w_ref[0].astype(jnp.int32)  # (Pt, q)
+    x = x_ref[0].astype(jnp.int32)  # (Bt, Pt)
+    z = z_ref[0].astype(jnp.int32)  # (Bt, q)
     net_ref[...] += stdp_net_tile(
-        w, x, z, uu_ref[...], ud_ref[...],
+        w, x, z, uu_ref[0], ud_ref[0],
         T=T, w_max=w_max, table=table,
         mu_capture=mu_capture, mu_backoff=mu_backoff, mu_search=mu_search)
 
@@ -95,9 +95,9 @@ def _stdp_kernel(
         if out == "net":
             # Pre-clip counter deltas: the form that composes additively
             # across data shards (psum, then one saturating apply).
-            out_ref[...] = net_ref[...]
+            out_ref[0] = net_ref[...]
         else:
-            out_ref[...] = jnp.clip(w + net_ref[...], 0, w_max)
+            out_ref[0] = jnp.clip(w + net_ref[...], 0, w_max)
 
 
 @functools.partial(
@@ -125,18 +125,21 @@ def stdp_update_pallas(
     interpret: bool = False,
     out: str = "weights",
 ) -> jax.Array:
-    """w: (p, q) ints; x: (B, p); z: (B, q); u_*: (B, p, q) f32 uniforms.
+    """w: (C, p, q) ints; x: (C, B, p); z: (C, B, q); u_*: (C, B, p, q) f32
+    uniforms — every column of a layer in one column-major launch (the
+    column axis leads the grid, blocks are ``(1, rows, lanes)``).
 
-    ``out="weights"`` (default) returns the saturating-updated weights;
-    ``out="net"`` returns the raw batch-summed inc-dec counters *before*
-    the clip — the additive form sharded training psums over the mesh's
-    "data" axis before one final saturating apply (DESIGN.md §9).
+    ``out="weights"`` (default) returns the saturating-updated (C, p, q)
+    weights; ``out="net"`` returns the raw batch-summed inc-dec counters
+    *before* the clip — the additive form sharded training psums over the
+    mesh's "data" axis before one final saturating apply (DESIGN.md §9).
     """
     if out not in ("weights", "net"):
         raise ValueError(f"out={out!r}; one of ('weights', 'net')")
-    B, p = x.shape
-    q = z.shape[1]
-    assert w.shape == (p, q) and u_up.shape == (B, p, q) and u_dn.shape == (B, p, q)
+    C, B, p = x.shape
+    q = z.shape[2]
+    assert w.shape == (C, p, q) and z.shape == (C, B, q)
+    assert u_up.shape == (C, B, p, q) and u_dn.shape == (C, B, p, q)
     assert p % block_p == 0 and B % block_b == 0, (p, B, block_p, block_b)
     assert q <= 128
     if not table:
@@ -148,18 +151,20 @@ def stdp_update_pallas(
         mu_capture=mu_capture, mu_backoff=mu_backoff, mu_search=mu_search,
         n_b_tiles=n_b, out=out,
     )
+    u_spec = pl.BlockSpec((1, block_b, block_p, q),
+                          lambda c, s, b: (c, b, s, 0))
     return pl.pallas_call(
         kernel,
-        grid=(n_p, n_b),
+        grid=(C, n_p, n_b),
         in_specs=[
-            pl.BlockSpec((block_p, q), lambda s, b: (s, 0)),
-            pl.BlockSpec((block_b, block_p), lambda s, b: (b, s)),
-            pl.BlockSpec((block_b, q), lambda s, b: (b, 0)),
-            pl.BlockSpec((block_b, block_p, q), lambda s, b: (b, s, 0)),
-            pl.BlockSpec((block_b, block_p, q), lambda s, b: (b, s, 0)),
+            pl.BlockSpec((1, block_p, q), lambda c, s, b: (c, s, 0)),
+            pl.BlockSpec((1, block_b, block_p), lambda c, s, b: (c, b, s)),
+            pl.BlockSpec((1, block_b, q), lambda c, s, b: (c, b, 0)),
+            u_spec,
+            u_spec,
         ],
-        out_specs=pl.BlockSpec((block_p, q), lambda s, b: (s, 0)),
-        out_shape=jax.ShapeDtypeStruct((p, q), jnp.int32),
+        out_specs=pl.BlockSpec((1, block_p, q), lambda c, s, b: (c, s, 0)),
+        out_shape=jax.ShapeDtypeStruct((C, p, q), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_p, q), jnp.int32)],
         interpret=interpret,
     )(w.astype(jnp.int32), x.astype(jnp.int32), z.astype(jnp.int32),

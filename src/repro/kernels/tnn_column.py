@@ -12,16 +12,18 @@ The RNL body potential factors into a 0/1 matmul over the merged
     A[(b,t), (i,k)] = [x[b,i] + k <= t]      (built on the fly from x)
     N[(i,k), j]     = [k <= w[i,j]]          (built on the fly from w)
 
-so the MXU does the accumulation the pac_adder ripple chain does in silicon.
-Grid: (batch tiles, synapse tiles) with an f32 VMEM accumulator; on the
-last synapse tile the crossing time ``z = min{t : V >= theta}`` (and
+so the MXU does the accumulation the pac_adder ripple chain does in silicon
+(one bf16 0/1 matmul per ramp step k, see :func:`ramp_matmul`).
+Grid: (columns, batch tiles, synapse tiles) with an f32 VMEM accumulator;
+on the last synapse tile the crossing time ``z = min{t : V >= theta}`` (and
 optionally the WTA mask) is computed in-register and written out.
 
-Block shapes: x (Bt, Pt) int32, w (Pt, q) int32, out (Bt, q) int32. The
-A tile is (Bt*T, Pt*T) bf16 and N is (Pt*T, q) bf16 — with the default
-Bt=64, Pt=256, T=8 that is 4 MiB + 0.5 MiB, comfortably inside the ~16 MiB
-v5e VMEM alongside the (Bt*T, q) accumulator. q stays un-tiled (<= 128
-lanes covers every column in the paper; ops.py pads).
+Block shapes (column-major): x (1, Bt, Pt) int32, w (1, Pt, q) int32, out
+(1, Bt, q) int32. Each ramp step's A tile is (Bt*T, Pt) bf16 and its N
+tile (Pt, q) bf16 — with the default Bt=64, Pt=256, T=8 that is 256 KiB +
+64 KiB per step, well inside v5e VMEM alongside the (Bt*T, q)
+accumulator. q stays un-tiled (<= 128 lanes covers every column in the
+paper; ops.py pads).
 """
 from __future__ import annotations
 
@@ -37,26 +39,31 @@ def ramp_matmul(x: jax.Array, w: jax.Array, *, T: int) -> jax.Array:
     """One tile's RNL body-potential contribution as the §2 A@N matmul.
 
     x (Bt, Pt) i32 spike times; w (Pt, q) i32 weights -> (Bt*T, q) f32
-    partial potentials. Shared, parity-critical math: the per-layer column
-    kernel accumulates these across synapse tiles, the fused wave kernel
-    (:mod:`repro.kernels.tnn_wave`) consumes a single tile directly —
-    keeping ONE body keeps every backend bit-identical.
+    partial potentials, row ``b*T + t``. Shared, parity-critical math: the
+    per-layer column kernel accumulates these across synapse tiles, the
+    fused wave kernel (:mod:`repro.kernels.tnn_wave`) consumes a single
+    tile directly — keeping ONE body keeps every backend bit-identical.
+
+    The merged (synapse, ramp-step) contraction is split into one matmul
+    per ramp step k: ``A_k[(b,t), i] = [t - x[b,i] >= k]`` against
+    ``N_k[i, j] = [w[i,j] >= k]``. Neither operand merges axes into lanes
+    (Mosaic refuses that reshape), both are exact 0/1 in bf16, and the f32
+    accumulator counts at most Pt*T — exact. Step k = T never contributes
+    (``t - x <= T - 1``), so the loop stops at T - 1.
     """
     bt, p_tile = x.shape
-    q = w.shape[1]
-    k = jax.lax.broadcasted_iota(jnp.int32, (1, p_tile, T), 2) + 1  # ramp step 1..T
-    t = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)  # wave position 0..T-1
-    # A[(b,t),(i,k)] = [x + k <= t]  — (Bt, Pt, T) vs t -> (Bt, T, Pt*T)
-    arrive = x[:, :, None] + k  # (Bt, Pt, T): earliest t this ramp step contributes
-    a = (arrive.reshape(bt, 1, p_tile * T) <= t[:, :, None]).astype(jnp.bfloat16)
-    # N[(i,k), j] = [k <= w]
-    n = (k.reshape(p_tile, T, 1) <= w[:, None, :]).astype(jnp.bfloat16)
-    return jax.lax.dot_general(
-        a.reshape(bt * T, p_tile * T),
-        n.reshape(p_tile * T, q),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (Bt*T, q)
+    t = jax.lax.broadcasted_iota(jnp.int32, (bt, T, p_tile), 1)
+    # elapsed ramp steps at wave position t, one row per (b, t)
+    d = (t - x[:, None, :]).reshape(bt * T, p_tile)
+    v = jnp.zeros((bt * T, w.shape[1]), jnp.float32)
+    for k in range(1, T):
+        v += jax.lax.dot_general(
+            (d >= k).astype(jnp.bfloat16),
+            (w >= k).astype(jnp.bfloat16),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    return v
 
 
 def crossing_wta(V: jax.Array, *, T: int, theta: int, wta: bool) -> jax.Array:
@@ -81,22 +88,22 @@ def crossing_wta(V: jax.Array, *, T: int, theta: int, wta: bool) -> jax.Array:
 def _column_kernel(
     x_ref, w_ref, z_ref, acc_ref, *, T: int, theta: int, n_p_tiles: int, wta: bool
 ):
-    pt = pl.program_id(1)
+    pt = pl.program_id(2)
 
-    bt = x_ref.shape[0]
-    q = w_ref.shape[1]
+    bt = x_ref.shape[1]
+    q = w_ref.shape[2]
 
     @pl.when(pt == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)  # (Bt, Pt)
-    w = w_ref[...].astype(jnp.int32)  # (Pt, q)
+    x = x_ref[0].astype(jnp.int32)  # (Bt, Pt)
+    w = w_ref[0].astype(jnp.int32)  # (Pt, q)
     acc_ref[...] += ramp_matmul(x, w, T=T)
 
     @pl.when(pt == n_p_tiles - 1)
     def _finish():
-        z_ref[...] = crossing_wta(
+        z_ref[0] = crossing_wta(
             acc_ref[...].reshape(bt, T, q), T=T, theta=theta, wta=wta)
 
 
@@ -115,13 +122,17 @@ def column_forward_pallas(
     block_p: int = 256,
     interpret: bool = False,
 ) -> jax.Array:
-    """x: (B, p) int times in [0, T]; w: (p, q) int weights. Returns (B, q) i32.
+    """x: (C, B, p) int times in [0, T]; w: (C, p, q) int weights.
+    Returns (C, B, q) i32 — every column of a layer in one launch.
 
-    Requires B % block_b == 0, p % block_p == 0, q <= 128 (ops.py pads).
+    Column-major like the fused wave: the column axis is the leading grid
+    dimension and each block is ``(1, rows, lanes)``, so the last two block
+    dims are the tiled (batch, synapse) extents Mosaic requires. Requires
+    B % block_b == 0, p % block_p == 0, q <= 128 (ops.py pads).
     """
-    B, p = x.shape
-    p2, q = w.shape
-    assert p == p2, (p, p2)
+    C, B, p = x.shape
+    C2, p2, q = w.shape
+    assert (C, p) == (C2, p2), (x.shape, w.shape)
     assert B % block_b == 0 and p % block_p == 0, (B, p, block_b, block_p)
     assert q <= 128, "q is kept un-tiled; pad/partition columns beyond 128 neurons"
 
@@ -131,13 +142,13 @@ def column_forward_pallas(
     )
     return pl.pallas_call(
         kernel,
-        grid=(n_b, n_p),
+        grid=(C, n_b, n_p),
         in_specs=[
-            pl.BlockSpec((block_b, block_p), lambda b, s: (b, s)),
-            pl.BlockSpec((block_p, q), lambda b, s: (s, 0)),
+            pl.BlockSpec((1, block_b, block_p), lambda c, b, s: (c, b, s)),
+            pl.BlockSpec((1, block_p, q), lambda c, b, s: (c, s, 0)),
         ],
-        out_specs=pl.BlockSpec((block_b, q), lambda b, s: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, q), jnp.int32),
+        out_specs=pl.BlockSpec((1, block_b, q), lambda c, b, s: (c, b, 0)),
+        out_shape=jax.ShapeDtypeStruct((C, B, q), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_b * T, q), jnp.float32)],
         interpret=interpret,
     )(x.astype(jnp.int32), w.astype(jnp.int32))

@@ -166,8 +166,8 @@ def _prep_inputs(x, params, plan: NetworkPlan):
     the launch boundary as uint8 and the weights as int8 — 1/4 the
     HBM/VMEM bytes — and the kernel body widens to its i32 accumulator
     internally. An unpacked plan widens everything to i32 here, before the
-    launch (int8 VMEM tiles are Mosaic-fragile on some TPU generations, so
-    the wide layout stays selectable per config)."""
+    launch. Both layouts compile for a v5e at the prototype's widths
+    (``tests/test_tpu_compile.py`` covers the packed one)."""
     pad = plan.pad
     x_dt = jnp.uint8 if plan.packed else jnp.int32
     w_dt = jnp.int8 if plan.packed else jnp.int32

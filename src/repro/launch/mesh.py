@@ -13,20 +13,29 @@ import re
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
 
 _MESH_RE = re.compile(r"^(\d+)x(\d+)$")
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the repo's shard_maps and
+    jitted steps leave placement outside a shard_map to the compiler, which
+    ``jax.make_mesh``'s default ``Explicit`` axes refuse (an unresolved
+    gather sharding, a jit with no mesh context)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this host actually has (smoke tests / examples): 1D data mesh."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return _auto_mesh((n,), ("data",))
 
 
 def parse_mesh(spec: str) -> Tuple[int, int]:

@@ -45,12 +45,14 @@ from repro.configs import get_config, smoke_config
 from repro.launch.mesh import (
     describe, make_host_mesh, make_host_mesh_2d, parse_mesh,
 )
+from repro.launch.runtime import announce, enable_compile_cache
 
 
 def serve_lm(args: argparse.Namespace) -> None:
     from repro.models import model as M
     from repro.serve.engine import Engine, Request
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_host_mesh()
     print(f"serving {cfg.name} on {describe(mesh)}")
@@ -94,6 +96,7 @@ def serve_tnn(args: argparse.Namespace) -> None:
     from repro.serve.tnn_engine import ClassifyRequest, TNNEngine
     import jax.numpy as jnp
 
+    announce("serve")
     if args.mesh:
         mesh = make_host_mesh_2d(*parse_mesh(args.mesh))
     else:

@@ -30,6 +30,7 @@ from repro.launch.mesh import (
     describe, make_host_mesh, make_host_mesh_2d, make_production_mesh,
     parse_mesh,
 )
+from repro.launch.runtime import announce, enable_compile_cache
 from repro.models import model as M
 from repro.sharding import partition as PT
 from repro.sharding.context import use_partitioning
@@ -43,6 +44,7 @@ def train_tnn(args: argparse.Namespace) -> None:
     from repro.configs.tnn_mnist import launcher_network_config, train_config
     from repro.train.tnn_trainer import TNNTrainer
 
+    announce("train")
     sites = 16 if args.smoke and args.sites == 625 else args.sites
     cfg = launcher_network_config(sites, depth=args.depth, impl=args.impl,
                                   packed=args.packed)
@@ -123,6 +125,7 @@ def main() -> None:
         train_tnn(args)
         return
 
+    enable_compile_cache()
     args.ckpt_dir = args.ckpt_dir or "/tmp/repro_launch_ckpt"
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.production_mesh:

@@ -1,10 +1,5 @@
-"""Mesh partitioning: logical-axis rules, pipeline parallelism, and a
-version-portable ``shard_map``.
-
-``shard_map`` moved from ``jax.experimental.shard_map`` (kw ``check_rep``)
-to ``jax.shard_map`` (kw ``check_vma``) across jax releases; every in-repo
-SPMD entry point (pipeline stages, the TNN serving engine) goes through
-:func:`shard_map` here so the rest of the codebase is agnostic.
+"""Mesh partitioning: logical-axis rules, pipeline parallelism, and the
+one ``shard_map`` entry point every in-repo SPMD program goes through.
 """
 from __future__ import annotations
 
@@ -12,19 +7,14 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_replication: bool = False):
-    """``jax.shard_map`` / ``jax.experimental.shard_map.shard_map`` shim.
+    """``jax.shard_map`` with the repo's replication-check default.
 
-    ``check_replication=False`` maps to ``check_vma=False`` (new API) or
-    ``check_rep=False`` (old API): our staged functions produce replicated
-    outputs via explicit psums, which the checker cannot always prove.
+    ``check_replication=False`` maps to ``check_vma=False``: our staged
+    functions produce replicated outputs via explicit psums, which the
+    checker cannot always prove.
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_replication)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_replication)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_replication)
 
 
 __all__ = ["shard_map"]

@@ -94,6 +94,12 @@ def pipeline_apply(
         return jax.lax.psum(outs, axis)
 
     xm = x.reshape((n_micro, mb) + x.shape[1:])
+    # commit the operands to the mesh first: under a mesh with Explicit
+    # axes (``jax.make_mesh``'s default) a shard_map over arrays that sit
+    # on one device has no device assignment for the whole mesh
+    stacked_params = jax.device_put(stacked_params,
+                                    NamedSharding(mesh, P(axis)))
+    xm = jax.device_put(xm, NamedSharding(mesh, P()))
     fn = shard_map(
         staged, mesh=mesh,
         in_specs=(P(axis), P()),

@@ -7,9 +7,10 @@ from proptest import sharded_subprocess
 
 SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
     from repro.sharding.pipeline import pipeline_apply
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
     R, B, D = 8, 16, 32
     k1, k2 = jax.random.split(jax.random.PRNGKey(0))
     params = {"w": 0.3 * jax.random.normal(k1, (R, D, D)),
