@@ -32,6 +32,7 @@ from repro.core.column import (
 from repro.core.stdp import stdp_net_from_uniforms, stdp_update
 from repro.core.temporal import SPIKE_DTYPE, WaveSpec
 from repro.kernels import ops as _kops
+from repro.utils import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,8 +63,10 @@ def layer_forward(x: jax.Array, w: jax.Array, cfg: LayerConfig) -> jax.Array:
     """x: (B, n_cols, p) -> post-WTA spike times (B, n_cols, q)."""
     spec = cfg.column.wave
     if cfg.column.impl in ("pallas", "fused"):
-        z = _kops.layer_forward_fused(x, w, theta=cfg.column.theta, T=spec.T)
-        return z.astype(SPIKE_DTYPE)
+        with jax.named_scope(tracing.WAVE):
+            z = _kops.layer_forward_fused(x, w, theta=cfg.column.theta,
+                                          T=spec.T)
+            return z.astype(SPIKE_DTYPE)
     fwd = column_forward_matmul if cfg.column.impl == "matmul" else column_forward
 
     def one_col(xc, wc):
@@ -80,10 +83,11 @@ def layer_uniforms(key: jax.Array, cfg: LayerConfig, B: int) -> jax.Array:
     wave executor all consume these exact draws (u[:, 0] = up, u[:, 1] =
     down), which is what makes their updates bit-identical."""
     p, q = cfg.column.p, cfg.column.q
-    col_keys = jax.random.split(key, cfg.n_cols)
-    return jax.vmap(
-        lambda kk: jax.random.uniform(kk, (2, B, p, q), dtype=jnp.float32)
-    )(col_keys)
+    with jax.named_scope(tracing.UNIFORMS):
+        col_keys = jax.random.split(key, cfg.n_cols)
+        return jax.vmap(
+            lambda kk: jax.random.uniform(kk, (2, B, p, q), dtype=jnp.float32)
+        )(col_keys)
 
 
 def layer_step(

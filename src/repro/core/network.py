@@ -34,6 +34,7 @@ from repro.core.stdp import STDPConfig, apply_net
 from repro.core.temporal import SPIKE_DTYPE, WaveSpec
 from repro.kernels import padding as _kpad
 from repro.kernels import tnn_wave as _ktw
+from repro.utils import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,14 +145,15 @@ def encode_images(images01: jax.Array, cfg: NetworkConfig) -> jax.Array:
     Strong contrast spikes early; zero contrast never spikes. The wave spec
     is validated against the whole cascade (:func:`input_wave_spec`)."""
     wave = input_wave_spec(cfg)
-    c = dog_filter(images01) * 3.0  # contrast gain
-    on = extract_patches(jnp.clip(c, 0.0, 1.0), cfg.patch_k)
-    off = extract_patches(jnp.clip(-c, 0.0, 1.0), cfg.patch_k)
-    t_on = jnp.round((1.0 - on) * wave.T)
-    t_off = jnp.round((1.0 - off) * wave.T)
-    out = jnp.stack([t_on, t_off], axis=-1).reshape(
-        on.shape[0], on.shape[1], on.shape[2] * 2)
-    return out.astype(SPIKE_DTYPE)
+    with jax.named_scope(tracing.ENCODE):
+        c = dog_filter(images01) * 3.0  # contrast gain
+        on = extract_patches(jnp.clip(c, 0.0, 1.0), cfg.patch_k)
+        off = extract_patches(jnp.clip(-c, 0.0, 1.0), cfg.patch_k)
+        t_on = jnp.round((1.0 - on) * wave.T)
+        t_off = jnp.round((1.0 - off) * wave.T)
+        out = jnp.stack([t_on, t_off], axis=-1).reshape(
+            on.shape[0], on.shape[1], on.shape[2] * 2)
+        return out.astype(SPIKE_DTYPE)
 
 
 def _uses_fused_wave(cfg: NetworkConfig) -> bool:
@@ -198,15 +200,16 @@ def network_train_wave(
     rng: jax.Array,
 ) -> Tuple[List[jax.Array], List[jax.Array]]:
     """One unsupervised gamma wave through the whole network (all layers learn)."""
-    keys = jax.random.split(rng, len(cfg.layers))
+    with jax.named_scope(tracing.UNIFORMS):
+        keys = list(jax.random.split(rng, len(cfg.layers)))
     if _uses_fused_wave(cfg) and _fused_stdp_ready(cfg):
         B = x.shape[0]
         plan = _kpad.network_plan(cfg, B)
-        us = tuple(layer_uniforms(k, lcfg, B)
-                   for lcfg, k in zip(cfg.layers, keys))
-        zs, nets = _ktw.wave_train(
-            x, tuple(params), tuple((u[:, 0], u[:, 1]) for u in us),
-            plan=plan)
+        with jax.named_scope(tracing.UNIFORMS):
+            us = [layer_uniforms(k, lcfg, B)
+                  for lcfg, k in zip(cfg.layers, keys)]
+            us = tuple((u[:, 0], u[:, 1]) for u in us)
+        zs, nets = _ktw.wave_train(x, tuple(params), us, plan=plan)
         return (
             [z.astype(SPIKE_DTYPE) for z in zs],
             [apply_net(w, net, lcfg.column.wave)
@@ -294,7 +297,8 @@ def superbatch_keys(rng: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
         key, sub = jax.random.split(key)
         return key, sub
 
-    return jax.lax.scan(body, rng, None, length=k)
+    with jax.named_scope(tracing.UNIFORMS):
+        return jax.lax.scan(body, rng, None, length=k)
 
 
 def network_mesh_spec(cfg: NetworkConfig, mesh) -> _kpad.MeshSpec:
@@ -474,16 +478,27 @@ def network_train_step(
     site0 = (0 if model_axis is None
              else jax.lax.axis_index(model_axis) * c_local)
 
-    def shard_u(u):
-        # u: (C_global, 2, B, p, q) global draws -> this shard's
-        # (c_local, 2, b_local, p, q) slice. Site axis first (pad with the
-        # no-op 1.0 so the model multiple divides), then batch rows.
-        if model_axis is not None:
-            u = _kpad.pad_uniform_sites(u, c_local * model_shards)
-            u = jax.lax.dynamic_slice_in_dim(u, site0, c_local, axis=0)
-        return jax.lax.dynamic_slice_in_dim(u, row0, b_local, axis=2)
+    def draw(key, lcfg):
+        # this shard's (c_local, 2, b_local, p, q) slice of the layer's
+        # (C_global, 2, B, p, q) global draws, split into up and down. Site
+        # axis first (pad with the no-op 1.0 so the model multiple
+        # divides), then batch rows.
+        with jax.named_scope(tracing.UNIFORMS):
+            u = layer_uniforms(key, lcfg, B)
+            if model_axis is not None:
+                u = _kpad.pad_uniform_sites(u, c_local * model_shards)
+                u = jax.lax.dynamic_slice_in_dim(u, site0, c_local, axis=0)
+            u = jax.lax.dynamic_slice_in_dim(u, row0, b_local, axis=2)
+            return u[:, 0], u[:, 1]
 
-    keys = jax.random.split(rng, len(cfg.layers))
+    def psum(net):
+        if axis_name is None:
+            return net
+        with jax.named_scope(tracing.PSUM):
+            return jax.lax.psum(net, axis_name)
+
+    with jax.named_scope(tracing.UNIFORMS):
+        keys = list(jax.random.split(rng, len(cfg.layers)))
     if _uses_fused_wave(cfg) and _fused_stdp_ready(cfg):
         # One megakernel launch for the whole wave, any depth (DESIGN.md
         # §10, §11), gridded over the LOCAL site slice. The uniforms are
@@ -491,13 +506,9 @@ def network_train_step(
         # per-column key split and sliced per shard, and the counters
         # still psum — bits identical to the per-layer path.
         plan = _kpad.network_plan(cfg, b_local, n_cols=c_local)
-        us = [shard_u(layer_uniforms(k, lcfg, B))
-              for lcfg, k in zip(cfg.layers, keys)]
-        zs, nets = _ktw.wave_train(
-            x, tuple(params), tuple((u[:, 0], u[:, 1]) for u in us),
-            plan=plan)
-        if axis_name is not None:
-            nets = [jax.lax.psum(net, axis_name) for net in nets]
+        us = tuple(draw(k, lcfg) for lcfg, k in zip(cfg.layers, keys))
+        zs, nets = _ktw.wave_train(x, tuple(params), us, plan=plan)
+        nets = [psum(net) for net in nets]
         return (
             [z.astype(SPIKE_DTYPE) for z in zs],
             [apply_net(w, net, lcfg.column.wave)
@@ -506,10 +517,8 @@ def network_train_step(
     new_params, outs = [], []
     for w, lcfg, k in zip(params, cfg.layers, keys):
         z = layer_forward(x, w, lcfg)
-        u = shard_u(layer_uniforms(k, lcfg, B))  # global draws, local slice
-        net = layer_stdp_net(x, z, w, lcfg, u[:, 0], u[:, 1])
-        if axis_name is not None:
-            net = jax.lax.psum(net, axis_name)
+        u_up, u_dn = draw(k, lcfg)  # global draws, local slice
+        net = psum(layer_stdp_net(x, z, w, lcfg, u_up, u_dn))
         w = apply_net(w, net, lcfg.column.wave)
         new_params.append(w)
         outs.append(z)
@@ -545,7 +554,8 @@ def make_train_step(cfg: NetworkConfig, mesh=None, donate: bool = True):
         params = params_from_tree(
             state["params"], cfg,
             n_cols=x.shape[1] if spec.n_model > 1 else None)
-        key, sub = jax.random.split(state["rng"])
+        with jax.named_scope(tracing.UNIFORMS):
+            key, sub = jax.random.split(state["rng"])
         outs, new_params = network_train_step(
             x, params, cfg, sub,
             axis_name=spec.data_axis, data_shards=spec.n_data,
@@ -622,7 +632,8 @@ def make_online_step(cfg: NetworkConfig, mesh=None, donate: bool = True):
         params = params_from_tree(
             state["params"], cfg,
             n_cols=x.shape[1] if spec.n_model > 1 else None)
-        key, sub = jax.random.split(state["rng"])
+        with jax.named_scope(tracing.UNIFORMS):
+            key, sub = jax.random.split(state["rng"])
         _, new_params = network_train_step(
             x, params, cfg, sub,
             axis_name=spec.data_axis, data_shards=spec.n_data,
@@ -768,19 +779,21 @@ def classify(z_last: jax.Array, vote_table: jax.Array, T: int,
     posterior P(c | site, winner) — in hardware a small per-neuron LUT
     feeding the vote counters; ``soft=False`` is the plain majority vote of
     argmax site labels."""
-    winner, fired = winner_map(z_last, T)  # (B, S)
-    n_classes = vote_table.shape[-1]
-    S = vote_table.shape[0]
-    if soft:
-        post = vote_table / jnp.maximum(
-            vote_table.sum(axis=-1, keepdims=True), 1.0)  # (S, q, C)
-        votes = post[jnp.arange(S)[None, :], winner]  # (B, S, C)
-        votes = votes * fired[..., None]
+    with jax.named_scope(tracing.CLASSIFY):
+        winner, fired = winner_map(z_last, T)  # (B, S)
+        n_classes = vote_table.shape[-1]
+        S = vote_table.shape[0]
+        if soft:
+            post = vote_table / jnp.maximum(
+                vote_table.sum(axis=-1, keepdims=True), 1.0)  # (S, q, C)
+            votes = post[jnp.arange(S)[None, :], winner]  # (B, S, C)
+            votes = votes * fired[..., None]
+            return jnp.argmax(votes.sum(axis=1), axis=-1)
+        site_label = jnp.argmax(vote_table, axis=-1)  # (S, q)
+        lab = site_label[jnp.arange(S)[None, :], winner]  # (B, S)
+        votes = (jax.nn.one_hot(lab, n_classes, dtype=jnp.float32)
+                 * fired[..., None])
         return jnp.argmax(votes.sum(axis=1), axis=-1)
-    site_label = jnp.argmax(vote_table, axis=-1)  # (S, q)
-    lab = site_label[jnp.arange(S)[None, :], winner]  # (B, S)
-    votes = jax.nn.one_hot(lab, n_classes, dtype=jnp.float32) * fired[..., None]
-    return jnp.argmax(votes.sum(axis=1), axis=-1)
 
 
 def winner_bits(z_last: jax.Array, T: int) -> jax.Array:

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.temporal import WaveSpec
+from repro.utils import tracing
 
 
 def default_stabilize_table(w_max: int) -> Tuple[float, ...]:
@@ -189,7 +190,9 @@ def stdp_net_from_uniforms(
 def apply_net(weights: jax.Array, net: jax.Array, spec: WaveSpec) -> jax.Array:
     """Saturating counter apply: clip(w + net, 0, w_max) as int8 — the
     ``syn_weight_update`` FSM once per wave, after counter aggregation."""
-    return jnp.clip(weights.astype(jnp.int32) + net, 0, spec.w_max).astype(jnp.int8)
+    with jax.named_scope(tracing.APPLY):
+        return jnp.clip(weights.astype(jnp.int32) + net, 0,
+                        spec.w_max).astype(jnp.int8)
 
 
 def _single_wave(w, x, z, key, table, spec: WaveSpec, cfg: STDPConfig):

@@ -51,6 +51,7 @@ from repro.kernels.padding import PadPlan
 from repro.kernels.stdp_update import stdp_update_pallas
 from repro.kernels.tnn_column import column_forward_pallas
 from repro.kernels.wta import wta_pallas
+from repro.utils import tracing
 
 
 def column_forward(
@@ -148,13 +149,14 @@ def layer_forward_fused(
     B, _, p = x.shape
     plan = PadPlan.make(B, p, block_b=block_b, block_p=block_p,
                         interpret=interpret)
-    x = plan.pad_spikes(x, T, p_axis=2).transpose(1, 0, 2)
-    w = plan.pad_weights(w, p_axis=1)
-    z = column_forward_pallas(
-        x, w, theta=theta, T=T, wta=wta,
-        block_b=plan.block_b, block_p=plan.block_p, interpret=plan.interpret,
-    )
-    return z.transpose(1, 0, 2)[:B]
+    with jax.named_scope(tracing.WAVE):
+        x = plan.pad_spikes(x, T, p_axis=2).transpose(1, 0, 2)
+        w = plan.pad_weights(w, p_axis=1)
+        z = column_forward_pallas(
+            x, w, theta=theta, T=T, wta=wta, block_b=plan.block_b,
+            block_p=plan.block_p, interpret=plan.interpret,
+        )
+        return z.transpose(1, 0, 2)[:B]
 
 
 def layer_stdp_fused(
@@ -190,16 +192,17 @@ def layer_stdp_fused(
     B, _, p = x.shape
     plan = PadPlan.make(B, p, block_b=block_b, block_p=block_p,
                         interpret=interpret)
-    x = plan.pad_spikes(x, T, p_axis=2).transpose(1, 0, 2)
-    z = plan.pad_spikes(z, T).transpose(1, 0, 2)
-    w = plan.pad_weights(w, p_axis=1)
-    u_up = plan.pad_uniforms(u_up, b_axis=1, p_axis=2)
-    u_dn = plan.pad_uniforms(u_dn, b_axis=1, p_axis=2)
-    res = stdp_update_pallas(
-        w, x, z, u_up, u_dn,
-        T=T, w_max=w_max, table=tuple(table),
-        mu_capture=mu_capture, mu_backoff=mu_backoff, mu_search=mu_search,
-        block_p=plan.block_p, block_b=plan.block_b, interpret=plan.interpret,
-        out=out,
-    )
-    return res[:, :p]
+    with jax.named_scope(tracing.WAVE):
+        x = plan.pad_spikes(x, T, p_axis=2).transpose(1, 0, 2)
+        z = plan.pad_spikes(z, T).transpose(1, 0, 2)
+        w = plan.pad_weights(w, p_axis=1)
+        u_up = plan.pad_uniforms(u_up, b_axis=1, p_axis=2)
+        u_dn = plan.pad_uniforms(u_dn, b_axis=1, p_axis=2)
+        res = stdp_update_pallas(
+            w, x, z, u_up, u_dn,
+            T=T, w_max=w_max, table=tuple(table),
+            mu_capture=mu_capture, mu_backoff=mu_backoff,
+            mu_search=mu_search, block_p=plan.block_p, block_b=plan.block_b,
+            interpret=plan.interpret, out=out,
+        )
+        return res[:, :p]

@@ -167,5 +167,6 @@ def stdp_update_pallas(
         out_shape=jax.ShapeDtypeStruct((C, p, q), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_p, q), jnp.int32)],
         interpret=interpret,
+        name="tnn_stdp_update",
     )(w.astype(jnp.int32), x.astype(jnp.int32), z.astype(jnp.int32),
       u_up.astype(jnp.float32), u_dn.astype(jnp.float32))

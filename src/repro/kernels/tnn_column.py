@@ -151,4 +151,5 @@ def column_forward_pallas(
         out_shape=jax.ShapeDtypeStruct((C, B, q), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_b * T, q), jnp.float32)],
         interpret=interpret,
+        name="tnn_column_forward",
     )(x.astype(jnp.int32), w.astype(jnp.int32))

@@ -52,6 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.padding import NetworkPlan
 from repro.kernels.stdp_update import stdp_net_tile
 from repro.kernels.tnn_column import crossing_wta, ramp_matmul
+from repro.utils import tracing
 
 
 def _rnl_wta(x: jax.Array, w: jax.Array, *, T: int, theta: int) -> jax.Array:
@@ -154,6 +155,7 @@ def _wave_pallas_call(plan: NetworkPlan, learn: bool):
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=plan.pad.interpret,
+        name="tnn_wave_train" if learn else "tnn_wave_forward",
     )
 
 
@@ -187,9 +189,11 @@ def wave_forward(
     per-layer post-WTA spike times (z_i (B, C, q_i)) — uint8 under a
     packed plan, i32 otherwise; identical bits either way, and bit-exact
     with the per-layer backends at any depth."""
-    zs = _wave_pallas_call(plan, learn=False)(*_prep_inputs(x, params, plan))
-    B = plan.pad.b
-    return tuple(z.transpose(1, 0, 2)[:B] for z in zs)
+    with jax.named_scope(tracing.WAVE):
+        zs = _wave_pallas_call(plan, learn=False)(
+            *_prep_inputs(x, params, plan))
+        B = plan.pad.b
+        return tuple(z.transpose(1, 0, 2)[:B] for z in zs)
 
 
 @functools.partial(jax.jit, static_argnames=("plan",))
@@ -212,15 +216,16 @@ def wave_train(
     before one saturating ``apply_net``, so sharded training stays
     bit-identical."""
     pad = plan.pad
-    inputs = _prep_inputs(x, params, plan)
-    for i, (uu, ud) in enumerate(uniforms):
-        p_axis = 2 if i == 0 else None  # only layer 1's fan-in is padded
-        inputs.append(pad.pad_uniforms(uu, b_axis=1, p_axis=p_axis))
-        inputs.append(pad.pad_uniforms(ud, b_axis=1, p_axis=p_axis))
-    outs = _wave_pallas_call(plan, learn=True)(*inputs)
-    n = plan.n_layers
-    zs, nets = outs[:n], outs[n:]
-    B, p1 = pad.b, pad.p
-    zs = tuple(z.transpose(1, 0, 2)[:B] for z in zs)
-    nets = (nets[0][:, :p1],) + tuple(nets[1:])
-    return zs, nets
+    with jax.named_scope(tracing.WAVE):
+        inputs = _prep_inputs(x, params, plan)
+        for i, (uu, ud) in enumerate(uniforms):
+            p_axis = 2 if i == 0 else None  # only layer 1's fan-in is padded
+            inputs.append(pad.pad_uniforms(uu, b_axis=1, p_axis=p_axis))
+            inputs.append(pad.pad_uniforms(ud, b_axis=1, p_axis=p_axis))
+        outs = _wave_pallas_call(plan, learn=True)(*inputs)
+        n = plan.n_layers
+        zs, nets = outs[:n], outs[n:]
+        B, p1 = pad.b, pad.p
+        zs = tuple(z.transpose(1, 0, 2)[:B] for z in zs)
+        nets = (nets[0][:, :p1],) + tuple(nets[1:])
+        return zs, nets

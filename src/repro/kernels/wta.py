@@ -40,4 +40,5 @@ def wta_pallas(
         out_specs=pl.BlockSpec((block_b, q), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, q), jnp.int32),
         interpret=interpret,
+        name="tnn_wta",
     )(z.astype(jnp.int32))

@@ -1,5 +1,6 @@
 """Process set-up shared by the launchers and ``chip_smoke.py``: the
-persistent compile cache and the one-line device report.
+persistent compile cache, the one-line device report, and the launchers'
+``--profile DIR``.
 
 Both run before the first compile. The cache follows
 ``JAX_COMPILATION_CACHE_DIR`` when it is set (JAX reads the variable
@@ -9,9 +10,10 @@ finds the first run's executables.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 from pathlib import Path
-from typing import Any, Dict
+from typing import Any, Dict, Iterator, Optional
 
 import jax
 
@@ -48,3 +50,21 @@ def announce(tag: str) -> Dict[str, Any]:
           f"devices={rep['count']} interpret={rep['interpret']} "
           f"compile_cache={cache}", flush=True)
     return rep
+
+
+@contextlib.contextmanager
+def profiled(log_dir: Optional[str]) -> Iterator[None]:
+    """Run the body under ``jax.profiler.trace(log_dir)``, or as it is when
+    ``log_dir`` is empty: a launcher's ``--profile DIR``. The profile holds
+    the device ops under their ``tnn.*`` scopes and the trainer's and
+    engine's ``tnn.*`` host spans (``utils/tracing.py``) on one clock. The
+    Python function tracer stays off: it would record every Python call and
+    slow the host loop it is meant to show."""
+    if not log_dir:
+        yield
+        return
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(log_dir, profiler_options=opts):
+        yield
+    print(f"[profile] written under {log_dir}", flush=True)

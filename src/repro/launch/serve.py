@@ -32,6 +32,9 @@ land back in the same directory:
     PYTHONPATH=src python -m repro.launch.serve --arch tnn-mnist \
         --from-ckpt /tmp/tnn_ckpt --sites 16 --requests 64 \
         --online-stdp --swap-every 4
+
+``--profile DIR`` records the run with the JAX profiler: the device ops
+under their ``tnn.*`` scopes and the engine's ``tnn.*`` host spans.
 """
 from __future__ import annotations
 
@@ -45,7 +48,7 @@ from repro.configs import get_config, smoke_config
 from repro.launch.mesh import (
     describe, make_host_mesh, make_host_mesh_2d, parse_mesh,
 )
-from repro.launch.runtime import announce, enable_compile_cache
+from repro.launch.runtime import announce, enable_compile_cache, profiled
 
 
 def serve_lm(args: argparse.Namespace) -> None:
@@ -153,7 +156,8 @@ def serve_tnn(args: argparse.Namespace) -> None:
           f"({mode}), accuracy {acc:.1%}")
     print(f"[serve-stats] {st.waves_per_s:.1f} waves/s  "
           f"{st.images_per_s:.1f} images/s  p50 {st.p50_ms:.1f} ms  "
-          f"p95 {st.p95_ms:.1f} ms  occupancy {st.occupancy:.0%}")
+          f"p95 {st.p95_ms:.1f} ms  occupancy {st.occupancy:.0%}  "
+          f"compiles {st.compiles}")
     if args.online_stdp:
         print(f"[online-stdp] learned to wave "
               f"{int(eng.learn_state['wave'])}, {eng.swaps} hot swap(s), "
@@ -224,11 +228,16 @@ def main() -> None:
                          "table at the shadow weights, checkpoints, and "
                          "publishes atomically; 0 swaps only on explicit "
                          "hot_swap() calls (DESIGN.md §15)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="record the run with the JAX profiler into DIR: "
+                         "device ops under their tnn.* scopes and the "
+                         "engine's tnn.* host spans on one clock")
     args = ap.parse_args()
-    if args.arch == "tnn-mnist":
-        serve_tnn(args)
-    else:
-        serve_lm(args)
+    with profiled(args.profile):
+        if args.arch == "tnn-mnist":
+            serve_tnn(args)
+        else:
+            serve_lm(args)
 
 
 if __name__ == "__main__":

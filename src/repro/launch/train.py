@@ -15,6 +15,9 @@ bit-exactly (re-run the same command to continue a run):
 
     PYTHONPATH=src python -m repro.launch.train --arch tnn-mnist --smoke \
         --epochs 1 --ckpt-dir /tmp/tnn_ckpt
+
+``--profile DIR`` records the run with the JAX profiler: the device ops
+under their ``tnn.*`` scopes and the trainer's ``tnn.*`` host spans.
 """
 from __future__ import annotations
 
@@ -30,7 +33,7 @@ from repro.launch.mesh import (
     describe, make_host_mesh, make_host_mesh_2d, make_production_mesh,
     parse_mesh,
 )
-from repro.launch.runtime import announce, enable_compile_cache
+from repro.launch.runtime import announce, enable_compile_cache, profiled
 from repro.models import model as M
 from repro.sharding import partition as PT
 from repro.sharding.context import use_partitioning
@@ -67,6 +70,47 @@ def train_tnn(args: argparse.Namespace) -> None:
           f"{tcfg.epochs} epoch(s) x {tcfg.waves_per_epoch} waves "
           f"x batch {tcfg.wave_batch}")
     trainer = TNNTrainer(cfg, tcfg, mesh=mesh)
+    print(trainer.run())
+
+
+def train_lm(args: argparse.Namespace) -> None:
+    enable_compile_cache()
+    args.ckpt_dir = args.ckpt_dir or "/tmp/repro_launch_ckpt"
+    cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    if args.production_mesh:
+        mesh = make_production_mesh(multi_pod=args.production_mesh == "multi")
+    else:
+        mesh = make_host_mesh()
+    print(f"training {cfg.name} on {describe(mesh)}")
+
+    prof = PT.RunProfile()
+    opt_cfg = OPT.OptConfig(
+        name=OPT.default_opt_for(cfg.n_params()), lr=args.lr,
+        warmup_steps=min(20, args.steps // 5 + 1), total_steps=args.steps,
+        compress_grads=args.compress_grads)
+    tc = TS.TrainConfig(micro_steps=args.micro_steps, kv_chunk=128)
+
+    state = TS.init_state(cfg, opt_cfg, jax.random.PRNGKey(0))
+    state_sh = PT.shardings_for_tree(
+        jax.eval_shape(lambda: state), TS.state_axes(cfg, opt_cfg), mesh,
+        PT.param_rules(mesh, prof))
+    state = jax.device_put(state, state_sh)
+
+    a_rules = PT.act_rules(mesh, prof)
+    raw_step = TS.make_train_step(cfg, opt_cfg, tc)
+
+    def step_fn(st, batch):
+        with mesh, use_partitioning(mesh, a_rules):
+            return jax.jit(raw_step, in_shardings=(state_sh, None),
+                           out_shardings=None)(st, batch)
+
+    stream = TokenStream(cfg.vocab_size, args.batch, args.seq, seed=0)
+    tcfg = TrainerConfig(total_steps=args.steps,
+                         ckpt_every=max(args.steps // 3, 5),
+                         ckpt_dir=args.ckpt_dir, log_every=5,
+                         metrics_path=args.ckpt_dir + "/metrics.jsonl")
+    trainer = Trainer(step_fn, state, stream, tcfg, shardings=state_sh)
+    trainer.install_preemption_handler()
     print(trainer.run())
 
 
@@ -119,50 +163,17 @@ def main() -> None:
                     help="waves between vote-table evals (0 = epoch ends)")
     ap.add_argument("--ckpt-every", type=int, default=0,
                     help="waves between checkpoints (0 = epoch ends)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="record the run with the JAX profiler into DIR: "
+                         "device ops under their tnn.* scopes and the "
+                         "trainer's tnn.* host spans on one clock")
     args = ap.parse_args()
 
-    if args.arch == "tnn-mnist":
-        train_tnn(args)
-        return
-
-    enable_compile_cache()
-    args.ckpt_dir = args.ckpt_dir or "/tmp/repro_launch_ckpt"
-    cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    if args.production_mesh:
-        mesh = make_production_mesh(multi_pod=args.production_mesh == "multi")
-    else:
-        mesh = make_host_mesh()
-    print(f"training {cfg.name} on {describe(mesh)}")
-
-    prof = PT.RunProfile()
-    opt_cfg = OPT.OptConfig(
-        name=OPT.default_opt_for(cfg.n_params()), lr=args.lr,
-        warmup_steps=min(20, args.steps // 5 + 1), total_steps=args.steps,
-        compress_grads=args.compress_grads)
-    tc = TS.TrainConfig(micro_steps=args.micro_steps, kv_chunk=128)
-
-    state = TS.init_state(cfg, opt_cfg, jax.random.PRNGKey(0))
-    state_sh = PT.shardings_for_tree(
-        jax.eval_shape(lambda: state), TS.state_axes(cfg, opt_cfg), mesh,
-        PT.param_rules(mesh, prof))
-    state = jax.device_put(state, state_sh)
-
-    a_rules = PT.act_rules(mesh, prof)
-    raw_step = TS.make_train_step(cfg, opt_cfg, tc)
-
-    def step_fn(st, batch):
-        with mesh, use_partitioning(mesh, a_rules):
-            return jax.jit(raw_step, in_shardings=(state_sh, None),
-                           out_shardings=None)(st, batch)
-
-    stream = TokenStream(cfg.vocab_size, args.batch, args.seq, seed=0)
-    tcfg = TrainerConfig(total_steps=args.steps,
-                         ckpt_every=max(args.steps // 3, 5),
-                         ckpt_dir=args.ckpt_dir, log_every=5,
-                         metrics_path=args.ckpt_dir + "/metrics.jsonl")
-    trainer = Trainer(step_fn, state, stream, tcfg, shardings=state_sh)
-    trainer.install_preemption_handler()
-    print(trainer.run())
+    with profiled(args.profile):
+        if args.arch == "tnn-mnist":
+            train_tnn(args)
+        else:
+            train_lm(args)
 
 
 if __name__ == "__main__":

@@ -72,11 +72,18 @@ requests dropped, duplicated, or classified against a half-published
 version. Requests record the version they were classified under;
 :meth:`TNNEngine.stats_by_version` splits the latency/occupancy record per
 version (the A/B surface ``tools/loadgen.py``'s labelled probe reads).
+
+For operators, admission, staging, dispatch, retire and hot swap run in
+``tnn.admit``/``tnn.stage``/``tnn.dispatch``/``tnn.retire``/``tnn.hot_swap``
+host spans (``utils/tracing.py``; ``launch/serve.py --profile DIR`` records
+them), and ``ServeStats.compiles`` counts the executables obtained since
+construction or :meth:`TNNEngine.reset`: a warm engine reads 0.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -102,6 +109,7 @@ from repro.core.network import (
 )
 from repro.kernels.padding import pad_batch_rows
 from repro.sharding import shard_map
+from repro.utils import tracing
 
 
 @dataclasses.dataclass
@@ -125,7 +133,9 @@ class ClassifyRequest:
 class ServeStats:
     """Aggregate serving record (DESIGN.md §12). ``wall_s`` spans first
     dispatch to last retire; occupancy is served rows over offered slot
-    rows (``waves * n_slots``) — 1.0 means every wave ran full."""
+    rows (``waves * n_slots``) — 1.0 means every wave ran full.
+    ``compiles``: executables obtained since construction or ``reset``
+    (``None`` in the per-version split, which does not divide them)."""
 
     requests: int
     waves: int
@@ -135,6 +145,7 @@ class ServeStats:
     p50_ms: float
     p95_ms: float
     occupancy: float
+    compiles: Optional[int] = None
 
 
 class ServeTimeout(RuntimeError):
@@ -335,6 +346,7 @@ class TNNEngine:
                 self._forward_sb = jax.jit(inner_k)
         self._classify = jax.jit(
             lambda z, vt: classify(z, vt, self.T, soft=True))
+        self._compiles0 = tracing.compile_count()
 
     # -- published snapshot (DESIGN.md §15) --------------------------------
 
@@ -480,19 +492,20 @@ class TNNEngine:
                                "from_checkpoint before serving")
 
     def _admit(self) -> List[ClassifyRequest]:
-        admitted: List[ClassifyRequest] = []
-        while self.queue and len(admitted) < self.n_slots:
-            admitted.append(self.queue.popleft())
-        return admitted
+        """FIFO-admit one wave: up to ``n_slots`` queued requests."""
+        return (self._admit_waves(1) or [[]])[0]
 
+    @functools.partial(jax.profiler.annotate_function, name=tracing.ADMIT)
     def _admit_waves(self, max_waves: int) -> List[List[ClassifyRequest]]:
         """FIFO-admit up to ``max_waves`` full-or-partial waves of queued
         requests (only the LAST wave of a dispatch may be partial)."""
         waves: List[List[ClassifyRequest]] = []
         while self.queue and len(waves) < max_waves:
-            waves.append(self._admit())
+            n = min(self.n_slots, len(self.queue))
+            waves.append([self.queue.popleft() for _ in range(n)])
         return waves
 
+    @functools.partial(jax.profiler.annotate_function, name=tracing.STAGE)
     def _stage_wave(self, admitted: List[ClassifyRequest]) -> jax.Array:
         """Host-stack + jitted-encode + no-op-pad one wave's images to the
         fixed (n_slots, S, p) spike shape — the same staging (same encode
@@ -502,6 +515,7 @@ class TNNEngine:
             [np.asarray(r.image, np.float32) for r in admitted]))
         return pad_batch_rows(self._encode(imgs), self.n_slots, self.T)
 
+    @functools.partial(jax.profiler.annotate_function, name=tracing.DISPATCH)
     def _dispatch(self, admitted: List[ClassifyRequest]) -> jax.Array:
         """Stage one wave and launch it asynchronously: host-side image
         stacking, jitted encode, no-op padding to the fixed slot shape,
@@ -525,6 +539,7 @@ class TNNEngine:
             req.version = ver
         return self._classify(z, vt)
 
+    @functools.partial(jax.profiler.annotate_function, name=tracing.DISPATCH)
     def _dispatch_super(self,
                         waves: List[List[ClassifyRequest]]) -> jax.Array:
         """Stage K admitted waves and launch them as ONE jitted scan
@@ -553,6 +568,7 @@ class TNNEngine:
         preds = self._classify(z_k.reshape(-1, *z_k.shape[2:]), vt)
         return preds.reshape(len(waves), self.n_slots)
 
+    @functools.partial(jax.profiler.annotate_function, name=tracing.RETIRE)
     def _retire(self, waves: List[List[ClassifyRequest]],
                 preds_dev: jax.Array) -> None:
         """Block on the dispatch's classify readout (the pipeline's ONLY
@@ -596,6 +612,7 @@ class TNNEngine:
         if self.swap_every and self._learn_waves >= self.swap_every:
             self.hot_swap()
 
+    @functools.partial(jax.profiler.annotate_function, name=tracing.HOT_SWAP)
     def hot_swap(self, block: bool = False) -> int:
         """Atomically publish the shadow weights as version v+1.
 
@@ -716,7 +733,8 @@ class TNNEngine:
 
     @staticmethod
     def _mk_stats(lat_ms: List[float], waves: int, wall: float,
-                  slots_filled: int, n_slots: int) -> ServeStats:
+                  slots_filled: int, n_slots: int,
+                  compiles: Optional[int] = None) -> ServeStats:
         served = len(lat_ms)
         lat = np.asarray(lat_ms, np.float64)
         return ServeStats(
@@ -728,6 +746,7 @@ class TNNEngine:
             p50_ms=float(np.percentile(lat, 50)) if served else 0.0,
             p95_ms=float(np.percentile(lat, 95)) if served else 0.0,
             occupancy=(slots_filled / (waves * n_slots)) if waves else 0.0,
+            compiles=compiles,
         )
 
     def stats(self) -> ServeStats:
@@ -736,7 +755,8 @@ class TNNEngine:
                 if self._t_first is not None and self._t_last is not None
                 else 0.0)
         return self._mk_stats(self._lat_ms, self.waves_served, wall,
-                              self._slots_filled, self.n_slots)
+                              self._slots_filled, self.n_slots,
+                              tracing.compile_count() - self._compiles0)
 
     def stats_by_version(self) -> Dict[int, ServeStats]:
         """The serve record split by published version (DESIGN.md §15):
@@ -767,4 +787,5 @@ class TNNEngine:
         self._lat_by_ver = {}
         self._waves_by_ver = {}
         self._slots_by_ver = {}
+        self._compiles0 = tracing.compile_count()
         self._span_by_ver = {}

@@ -36,8 +36,12 @@ silicon applies them — so its trainer drives gamma waves instead:
   ``TNNEngine.from_checkpoint`` warm-starts serving without a ``fit`` pass.
 * **unsupervised eval cadence** — on ``eval_every`` waves (and at the end)
   a labelled pass over the train set rebuilds the §1 vote-table readout and
-  scores held-out accuracy; waves/sec is tracked as the training-throughput
-  metric the benchmark-regression CI watches.
+  scores held-out accuracy.
+* **operator's view** — each dispatch is a ``tnn.train_wave`` profiler step
+  holding ``tnn.stage``/``tnn.step``/``tnn.block`` spans, and eval and
+  checkpoint run in ``tnn.eval``/``tnn.checkpoint`` (``utils/tracing.py``);
+  each JSONL record carries the dispatch's host time ``dt_s`` and
+  ``compiles``, the executables obtained since the previous record.
 
 Driver: ``python -m repro.launch.train --arch tnn-mnist [--smoke]``.
 """
@@ -70,6 +74,7 @@ from repro.core.network import (
     refresh_vote_table,
 )
 from repro.data.mnist_like import digits
+from repro.utils import tracing
 
 
 @dataclasses.dataclass
@@ -174,7 +179,6 @@ class TNNTrainer:
         self._eval_wave = -1  # wave the vote table was last built at
         self.ckpt = Checkpointer(tcfg.ckpt_dir, keep=tcfg.keep)
         self.accuracy: Optional[float] = None
-        self.wave_times: list = []
         self._forward = jax.jit(
             lambda ps, x: network_forward(x, ps, self.cfg)[-1])
         self._metrics_f = (open(tcfg.metrics_path, "a")
@@ -214,14 +218,15 @@ class TNNTrainer:
         return dict(self.state, vote_table=self.vote_table)
 
     def checkpoint(self, block: bool = False) -> None:
-        self.ckpt.save(
-            self.wave, self._ckpt_state(),
-            extra={"arch": "tnn-mnist",
-                   "config": tnn_config_fingerprint(self.cfg),
-                   "wave": self.wave, "has_vote": self.has_vote,
-                   "eval_wave": self._eval_wave,
-                   "accuracy": self.accuracy},
-            block=block)
+        with jax.profiler.TraceAnnotation(tracing.CHECKPOINT):
+            self.ckpt.save(
+                self.wave, self._ckpt_state(),
+                extra={"arch": "tnn-mnist",
+                       "config": tnn_config_fingerprint(self.cfg),
+                       "wave": self.wave, "has_vote": self.has_vote,
+                       "eval_wave": self._eval_wave,
+                       "accuracy": self.accuracy},
+                block=block)
 
     def maybe_resume(self) -> bool:
         latest = self.ckpt.latest_step()
@@ -250,17 +255,19 @@ class TNNTrainer:
         the one the serving engine's online hot swap also runs, so a
         swap-published readout matches the trainer's bit for bit
         (DESIGN.md §15)."""
-        T = self.cfg.layers[-1].column.wave.T
-        params = params_from_tree(self.state["params"], self.cfg)
-        self.vote_table = refresh_vote_table(
-            self._forward, params, self.stream.x, self.stream.labels,
-            self.cfg, self.tcfg.wave_batch)
-        self.has_vote = True
-        z_eval = self._forward_all(params, self.eval_stream.x)
-        preds = np.asarray(classify(z_eval, self.vote_table, T, soft=True))
-        self.accuracy = float((preds == self.eval_stream.labels).mean())
-        self._eval_wave = self.wave
-        return self.accuracy
+        with jax.profiler.TraceAnnotation(tracing.EVAL):
+            T = self.cfg.layers[-1].column.wave.T
+            params = params_from_tree(self.state["params"], self.cfg)
+            self.vote_table = refresh_vote_table(
+                self._forward, params, self.stream.x, self.stream.labels,
+                self.cfg, self.tcfg.wave_batch)
+            self.has_vote = True
+            z_eval = self._forward_all(params, self.eval_stream.x)
+            preds = np.asarray(classify(z_eval, self.vote_table, T,
+                                        soft=True))
+            self.accuracy = float((preds == self.eval_stream.labels).mean())
+            self._eval_wave = self.wave
+            return self.accuracy
 
     # -- the loop ----------------------------------------------------------
 
@@ -306,24 +313,30 @@ class TNNTrainer:
                   f"from {self.tcfg.ckpt_dir}")
         total = self.tcfg.total_waves
         wpe = self.tcfg.waves_per_epoch
+        compiles = tracing.compile_count()
         while self.wave < total:
             wave = self.wave
-            t0 = time.perf_counter()
-            if self.superbatch_fn is None:
-                k = 1
-                x = jnp.asarray(self.stream.batch_at(wave))
-                self.state, z = self.step_fn(self.state, x)
-            else:
-                k = self._chunk_k(wave, total)
-                x_k = jnp.asarray(self.stream.superbatch_at(wave, k))
-                self.state, z_k = self.superbatch_fn(self.state, x_k)
-                z = z_k[-1]  # the chunk-end wave's readout, like lock-step
-            jax.block_until_ready(z)
-            dt = time.perf_counter() - t0
-            self.wave_times.append(dt / k)
+            with jax.profiler.StepTraceAnnotation(tracing.TRAIN_WAVE,
+                                                  step_num=wave):
+                t0 = time.perf_counter()
+                if self.superbatch_fn is None:
+                    k = 1
+                    with jax.profiler.TraceAnnotation(tracing.STAGE):
+                        x = jnp.asarray(self.stream.batch_at(wave))
+                    with jax.profiler.TraceAnnotation(tracing.STEP):
+                        self.state, z = self.step_fn(self.state, x)
+                else:
+                    k = self._chunk_k(wave, total)
+                    with jax.profiler.TraceAnnotation(tracing.STAGE):
+                        x_k = jnp.asarray(self.stream.superbatch_at(wave, k))
+                    with jax.profiler.TraceAnnotation(tracing.STEP):
+                        self.state, z_k = self.superbatch_fn(self.state, x_k)
+                        z = z_k[-1]  # the chunk-end wave's readout
+                with jax.profiler.TraceAnnotation(tracing.BLOCK):
+                    jax.block_until_ready(z)
+                dt = time.perf_counter() - t0
             wave += k
             rec = {"wave": wave, "dt_s": round(dt, 4),
-                   "waves_per_s": round(k / max(dt, 1e-9), 3),
                    "fired": round(float((np.asarray(z) <
                                          self.cfg.layers[-1].column.wave.T)
                                         .mean()), 4)}
@@ -333,6 +346,8 @@ class TNNTrainer:
             if (self.tcfg.eval_every and wave % self.tcfg.eval_every == 0) or \
                     (not self.tcfg.eval_every and at_epoch_end):
                 rec["accuracy"] = self.evaluate()
+            now = tracing.compile_count()
+            rec["compiles"], compiles = now - compiles, now
             self._log(rec)
             if (self.tcfg.ckpt_every and wave % self.tcfg.ckpt_every == 0) or \
                     (not self.tcfg.ckpt_every and at_epoch_end):
@@ -349,11 +364,9 @@ class TNNTrainer:
         if did_final_eval or self.ckpt.latest_step() != self.wave:
             self.checkpoint(block=True)
             self.ckpt.wait()
-        med = float(np.median(self.wave_times)) if self.wave_times else 0.0
         return {
             "final_wave": self.wave,
             "epochs": self.wave // wpe,
             "accuracy": self.accuracy,
-            "waves_per_s": (1.0 / med) if med else None,
             "resumed": resumed,
         }
