@@ -81,13 +81,21 @@ def layer_uniforms(key: jax.Array, cfg: LayerConfig, B: int) -> jax.Array:
     drawn from the per-column key split EVERY backend uses — the per-layer
     vmap path, the layer-level pallas kernels and the whole-network fused
     wave executor all consume these exact draws (u[:, 0] = up, u[:, 1] =
-    down), which is what makes their updates bit-identical."""
+    down), which is what makes their updates bit-identical.
+
+    Each column draws (2, B, p*q) and only then reshapes to (2, B, p, q):
+    threefry's counter is the row-major flat index, so the bits are those
+    of drawing (2, B, p, q) directly. The barrier keeps the compiler from
+    folding the reshape back into the draw, whose (p, q)-minor layout
+    would fill 12 or 10 of a TPU tile's 128 lanes."""
     p, q = cfg.column.p, cfg.column.q
     with jax.named_scope(tracing.UNIFORMS):
         col_keys = jax.random.split(key, cfg.n_cols)
-        return jax.vmap(
-            lambda kk: jax.random.uniform(kk, (2, B, p, q), dtype=jnp.float32)
+        u = jax.vmap(
+            lambda kk: jax.random.uniform(kk, (2, B, p * q), dtype=jnp.float32)
         )(col_keys)
+        u = jax.lax.optimization_barrier(u)
+        return u.reshape(cfg.n_cols, 2, B, p, q)
 
 
 def layer_step(

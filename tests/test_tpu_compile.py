@@ -2,12 +2,14 @@
 
 Mosaic accepts or refuses a kernel at compile time, and the TPU compiler
 is installed even where no chip is attached, so these tests compile the
-fused wave and the per-layer forward at the prototype's real widths (625
-sites, depth 2, batch 16) with ``interpret=False`` — what the interpreter
-used by every other test cannot show. Nothing runs; a pass here is not a
+fused wave, the per-layer forward and the whole train step at the
+prototype's real widths (625 sites, depth 2, batch 16) with
+``interpret=False`` — what the interpreter used by every other test cannot
+show. Nothing runs; a pass here is not a
 chip run.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.tnn_mnist import launcher_network_config
+from repro.core.network import init_train_state, make_train_step
 from repro.kernels import ops, padding, tnn_wave
 
 SITES, B = 625, 16
@@ -77,3 +80,31 @@ def test_layer_forward_fused_compiles_for_v5e(one_chip):
     _, x, ws, _ = _prototype(one_chip)
     _compile(lambda x, w: ops.layer_forward_fused(
         x, w, theta=24, T=8, interpret=False), x, ws[0])
+
+
+def test_train_step_draws_uniforms_on_dense_lanes(one_chip, monkeypatch):
+    """The STDP uniforms are drawn per site over the flattened synapse axis
+    and kept so: a (p, q)-minor draw fills 12 or 10 of a tile's 128 lanes,
+    which made threefry hash about twelve times the elements it needs."""
+    monkeypatch.setattr(padding, "resolve_interpret",
+                        lambda interpret=None: bool(interpret))
+    cfg = launcher_network_config(SITES, depth=2, impl="fused")
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_train_state(jax.random.PRNGKey(0), cfg)))
+    x = jax.ShapeDtypeStruct((B, SITES, cfg.layers[0].column.p), jnp.uint8,
+                             sharding=one_chip)
+    text = make_train_step(cfg).lower(state, x).compile().as_text()
+    assert "tpu_custom_call" in text
+    fusions = []                          # (output shape, op_name)
+    for line in text[text.index("\nENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) fusion\(", line)
+        if m:
+            op = re.search(r'op_name="([^"]*)"', line)
+            fusions.append((m.group(1), op.group(1) if op else ""))
+    draws = {re.sub(r"\{.*", "", shape) for shape, op in fusions
+             if shape.startswith("f32[") and "tnn.uniforms" in op
+             and "_uniform" in op}
+    assert draws == {f"f32[{SITES},2,{B},384]", f"f32[{SITES},2,{B},120]"}
+    folded = f"f32[{SITES},2,{B},32,12]"
+    assert not [shape for shape, _ in fusions if folded in shape]
