@@ -27,7 +27,9 @@ from repro.core.network import (
     prototype_config,
     init_network,
     init_train_state,
+    encode,
     encode_images,
+    encode_series,
     forward_all_padded,
     input_wave_spec,
     make_online_step,
@@ -59,7 +61,8 @@ __all__ = [
     "column_step", "crossing_time", "init_weights", "wta_inhibit",
     "LayerConfig", "init_layer", "layer_forward", "layer_stdp_net", "layer_step",
     "NetworkConfig", "prototype_config", "init_network", "init_train_state",
-    "encode_images", "forward_all_padded", "input_wave_spec",
+    "encode", "encode_images", "encode_series", "forward_all_padded",
+    "input_wave_spec",
     "make_online_step", "make_online_superbatch_step", "make_superbatch_step",
     "make_train_step", "network_forward", "network_forward_superbatch",
     "network_train_step", "network_train_superbatch", "network_train_wave",

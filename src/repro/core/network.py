@@ -50,6 +50,12 @@ class NetworkConfig:
     # compile for a v5e, so the flag is a pure bytes/performance knob and is
     # deliberately excluded from the checkpoint config fingerprint.
     packed: bool = True
+    # The front end. ``None``: images, DoG contrast and on/off ``patch_k``
+    # patches over ``image_hw``, one site per patch. An int L: a 1-D series
+    # of L samples, one synapse line per sample into a single site — the
+    # time-series clustering column (arXiv 2102.09200). :func:`encode`
+    # picks the encoder from it.
+    series_len: Optional[int] = None
 
     def validate(self) -> None:
         for l in self.layers:
@@ -118,7 +124,7 @@ def dog_filter(images01: jax.Array) -> jax.Array:
 
 
 def input_wave_spec(cfg: NetworkConfig) -> WaveSpec:
-    """The wave spec the image encoder must encode against — validated, not
+    """The wave spec the encoder must encode against — validated, not
     silently ``cfg.layers[0]``: the encoder's time base is consumed by the
     whole cascade (the readout reads ``layers[-1]`` with the same T), so a
     network whose layers disagree on the spec has no well-defined encoding
@@ -129,10 +135,18 @@ def input_wave_spec(cfg: NetworkConfig) -> WaveSpec:
             f"encode_images needs one wave spec across the cascade, but the "
             f"layers disagree: {[(s.T, s.w_max) for s in specs]} — encoding "
             f"against layers[0] would silently mis-time every deeper layer")
+    first = cfg.layers[0]
+    if cfg.series_len is not None:
+        if first.n_cols != 1 or first.column.p != cfg.series_len:
+            raise ValueError(
+                f"a series front end of {cfg.series_len} samples feeds one "
+                f"site of {cfg.series_len} synapses, but the input-facing "
+                f"layer has {first.n_cols} sites of fan-in {first.column.p}")
+        return specs[0]
     p_in = 2 * cfg.patch_k ** 2
-    if cfg.layers[0].column.p != p_in:
+    if first.column.p != p_in:
         raise ValueError(
-            f"input-facing layer expects fan-in {cfg.layers[0].column.p}, "
+            f"input-facing layer expects fan-in {first.column.p}, "
             f"but a patch_k={cfg.patch_k} on/off front end produces "
             f"{p_in} synapses per site")
     return specs[0]
@@ -154,6 +168,39 @@ def encode_images(images01: jax.Array, cfg: NetworkConfig) -> jax.Array:
         out = jnp.stack([t_on, t_off], axis=-1).reshape(
             on.shape[0], on.shape[1], on.shape[2] * 2)
         return out.astype(SPIKE_DTYPE)
+
+
+# The series front end's levels, in standard deviations of a z-normalised
+# series: a sample spikes once |x| reaches SERIES_FLOOR, one tick earlier
+# for each SERIES_STEP further out. Both are exact in bfloat16 and float32.
+SERIES_FLOOR = 1.0
+SERIES_STEP = 0.25
+
+
+def encode_series(series: jax.Array, cfg: NetworkConfig) -> jax.Array:
+    """(B, L) z-normalised float32 series -> (B, 1, L) uint8 spike times.
+
+    A sample spikes the earlier the further it lies from its series' mean,
+    above or below: at the tick t that counts the levels SERIES_FLOOR +
+    k SERIES_STEP (k = 0 .. T-1) lying above |x|. So |x| >= SERIES_FLOOR +
+    (T-1) SERIES_STEP spikes at 0 and |x| < SERIES_FLOOR never spikes (time
+    T): a series' peaks and troughs are its spikes, the samples near its
+    mean are silent. Only compares of |x| against constants, with no
+    arithmetic that a backend could round differently."""
+    T = input_wave_spec(cfg).T
+    with jax.named_scope(tracing.ENCODE):
+        mag = jnp.abs(series.astype(jnp.float32))
+        t = sum((mag < jnp.float32(SERIES_FLOOR + (T - 1 - k) * SERIES_STEP)
+                 ).astype(jnp.int32) for k in range(T))
+        return t[:, None, :].astype(SPIKE_DTYPE)
+
+
+def encode(x: jax.Array, cfg: NetworkConfig) -> jax.Array:
+    """The network's front end: :func:`encode_series` for a series config,
+    :func:`encode_images` otherwise."""
+    if cfg.series_len is not None:
+        return encode_series(x, cfg)
+    return encode_images(x, cfg)
 
 
 def _uses_fused_wave(cfg: NetworkConfig) -> bool:

@@ -47,11 +47,28 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.padding import PadPlan
+from repro.kernels.padding import PadPlan, pad_to
 from repro.kernels.stdp_update import stdp_update_pallas
 from repro.kernels.tnn_column import column_forward_pallas
 from repro.kernels.wta import wta_pallas
 from repro.utils import tracing
+
+
+# VMEM for one STDP uniforms block, (1, block_b, block_p, q) f32 with q in
+# 128-lane tiles. The kernel double-buffers the up and down blocks, so four
+# of them lie in VMEM at once: 8 MiB of v5e's 16 MiB scoped default.
+STDP_U_BLOCK_BYTES = 2 << 20
+
+
+def stdp_block_b(p: int, q: int, *, block_b: int, block_p: int) -> int:
+    """The STDP launch's batch block: ``block_b``, or the most rows, a
+    multiple of 8, with which one uniforms block fits
+    :data:`STDP_U_BLOCK_BYTES`. Narrow fan-ins keep ``block_b`` (every
+    prototype layer at any batch up to 128); a wide one (1,882 synapses a
+    column) takes fewer rows a tile."""
+    rows = min(block_p, pad_to(p, 8)) * pad_to(q, 128) * 4
+    fit = max(STDP_U_BLOCK_BYTES // rows // 8 * 8, 8)
+    return min(block_b, fit)
 
 
 def column_forward(
@@ -109,6 +126,7 @@ def stdp_update(
     """Fused STDP wave update. Returns new (p, q) i32 weights, or the raw
     pre-clip (p, q) i32 net counters when ``out="net"`` (DESIGN.md §9)."""
     B, p = x.shape
+    block_b = stdp_block_b(p, w.shape[1], block_b=block_b, block_p=block_p)
     plan = PadPlan.make(B, p, block_b=block_b, block_p=block_p,
                         interpret=interpret)
     # padded batch rows: x=T & z=T -> 'none' case -> no update; padded
@@ -190,6 +208,7 @@ def layer_stdp_fused(
     step psums over the mesh's "data" axis (DESIGN.md §9).
     """
     B, _, p = x.shape
+    block_b = stdp_block_b(p, w.shape[2], block_b=block_b, block_p=block_p)
     plan = PadPlan.make(B, p, block_b=block_b, block_p=block_p,
                         interpret=interpret)
     with jax.named_scope(tracing.WAVE):

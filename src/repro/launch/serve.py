@@ -33,6 +33,13 @@ land back in the same directory:
         --from-ckpt /tmp/tnn_ckpt --sites 16 --requests 64 \
         --online-stdp --swap-every 4
 
+``--arch tnn-ucr-inlineskate`` serves the time-series clustering column
+(``configs/tnn_ucr.py``): each request is one series of UCR InlineSkate's
+shape, classified by its cluster's vote; ``--sites`` does not apply:
+
+    PYTHONPATH=src python -m repro.launch.serve --arch tnn-ucr-inlineskate \
+        --from-ckpt /tmp/tnn_ucr_ckpt --requests 64 --slots 16
+
 ``--profile DIR`` records the run with the JAX profiler: the device ops
 under their ``tnn.*`` scopes and the engine's ``tnn.*`` host spans.
 """
@@ -45,6 +52,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, smoke_config
+from repro.configs.tnn_launch import TNN_ARCHS, launcher_config
 from repro.launch.mesh import (
     describe, make_host_mesh, make_host_mesh_2d, parse_mesh,
 )
@@ -92,10 +100,21 @@ def resolve_slots(requested: int, ndata: int) -> int:
     return resolved
 
 
-def serve_tnn(args: argparse.Namespace) -> None:
-    from repro.configs.tnn_mnist import crop_field, launcher_network_config
-    from repro.core import init_network, network_train_wave, encode_images
+def tnn_inputs(args: argparse.Namespace, cfg, n: int, seed: int):
+    """``n`` labelled inputs for the config's front end: seeded series, or
+    digits cropped to the ``--sites`` field."""
+    from repro.configs.tnn_mnist import crop_field
     from repro.data.mnist_like import digits
+    from repro.data.series import series
+
+    if cfg.series_len is not None:
+        return series(n, cfg.series_len, cfg.n_classes, seed=seed)
+    imgs, labels = digits(n, seed=seed)
+    return crop_field(imgs, args.sites), labels
+
+
+def serve_tnn(args: argparse.Namespace) -> None:
+    from repro.core import encode, init_network, network_train_wave
     from repro.serve.tnn_engine import ClassifyRequest, TNNEngine
     import jax.numpy as jnp
 
@@ -109,12 +128,11 @@ def serve_tnn(args: argparse.Namespace) -> None:
     # only forward it when online learning is actually on
     swap_every = args.swap_every if args.online_stdp else 0
     n_slots = resolve_slots(args.slots, int(mesh.shape.get("data", 1)))
-    cfg = launcher_network_config(args.sites, depth=args.depth,
-                                  impl=args.impl, packed=args.packed)
-    print(f"serving tnn-mnist ({cfg.n_neurons:,} neurons, impl={args.impl}) "
-          f"on {describe(mesh)}")
-    lab_imgs, lab_labs = digits(max(128, 4 * n_slots), seed=1)
-    lab_imgs = crop_field(lab_imgs, args.sites)
+    cfg, _ = launcher_config(args.arch, args.sites, args.depth, args.impl,
+                             args.packed)
+    print(f"serving {args.arch} ({cfg.n_neurons:,} neurons, "
+          f"impl={args.impl}) on {describe(mesh)}")
+    lab_imgs, lab_labs = tnn_inputs(args, cfg, max(128, 4 * n_slots), seed=1)
     if args.from_ckpt:
         # trained deployment: weights + vote table from the training
         # checkpoint (rebuilt from label_data when the checkpoint predates
@@ -132,7 +150,7 @@ def serve_tnn(args: argparse.Namespace) -> None:
               f"warm-started from {args.from_ckpt}")
     else:
         params = init_network(jax.random.PRNGKey(0), cfg)
-        x = jnp.asarray(encode_images(jnp.asarray(lab_imgs), cfg))
+        x = jnp.asarray(encode(jnp.asarray(lab_imgs), cfg))
         key = jax.random.PRNGKey(1)
         for _ in range(args.train_waves):  # short unsupervised warm-up
             key, k = jax.random.split(key)
@@ -144,8 +162,7 @@ def serve_tnn(args: argparse.Namespace) -> None:
                         swap_every=swap_every)
         eng.fit(lab_imgs, lab_labs)
 
-    test_imgs, test_labs = digits(args.requests, seed=2)
-    test_imgs = crop_field(test_imgs, args.sites)
+    test_imgs, test_labs = tnn_inputs(args, cfg, args.requests, seed=2)
     for uid in range(args.requests):
         eng.submit(ClassifyRequest(uid=uid, image=test_imgs[uid]))
     done = eng.run_until_done(pipelined=not args.lockstep)
@@ -234,7 +251,7 @@ def main() -> None:
                          "engine's tnn.* host spans on one clock")
     args = ap.parse_args()
     with profiled(args.profile):
-        if args.arch == "tnn-mnist":
+        if args.arch in TNN_ARCHS:
             serve_tnn(args)
         else:
             serve_lm(args)

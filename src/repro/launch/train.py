@@ -16,6 +16,14 @@ bit-exactly (re-run the same command to continue a run):
     PYTHONPATH=src python -m repro.launch.train --arch tnn-mnist --smoke \
         --epochs 1 --ckpt-dir /tmp/tnn_ckpt
 
+``--arch tnn-ucr-inlineskate`` trains the time-series clustering column
+(one site, 1,882 synapses, 7 neurons; ``configs/tnn_ucr.py``) through the
+same trainer, on seeded series of UCR InlineSkate's shape; its vote-table
+accuracy is cluster purity:
+
+    PYTHONPATH=src python -m repro.launch.train --arch tnn-ucr-inlineskate \
+        --epochs 40 --ckpt-dir /tmp/tnn_ucr_ckpt
+
 ``--profile DIR`` records the run with the JAX profiler: the device ops
 under their ``tnn.*`` scopes and the trainer's ``tnn.*`` host spans.
 """
@@ -28,6 +36,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, smoke_config
+from repro.configs.tnn_launch import TNN_ARCHS, launcher_config
 from repro.data.tokens import TokenStream
 from repro.launch.mesh import (
     describe, make_host_mesh, make_host_mesh_2d, make_production_mesh,
@@ -43,21 +52,21 @@ from repro.train.trainer import Trainer, TrainerConfig
 
 
 def train_tnn(args: argparse.Namespace) -> None:
-    """Wave-batched online STDP over the prototype (DESIGN.md §9)."""
-    from repro.configs.tnn_mnist import launcher_network_config, train_config
+    """Wave-batched online STDP over the prototype or the time-series
+    column (DESIGN.md §9)."""
     from repro.train.tnn_trainer import TNNTrainer
 
     announce("train")
     sites = 16 if args.smoke and args.sites == 625 else args.sites
-    cfg = launcher_network_config(sites, depth=args.depth, impl=args.impl,
-                                  packed=args.packed)
+    cfg, train_config = launcher_config(args.arch, sites, args.depth,
+                                        args.impl, args.packed)
+    ckpt_dir = args.ckpt_dir or train_config().ckpt_dir
     if args.mesh:
         mesh = make_host_mesh_2d(*parse_mesh(args.mesh))
     else:
         mesh = make_host_mesh()
-    ckpt_dir = args.ckpt_dir or "/tmp/repro_tnn_ckpt"
     tcfg = train_config(
-        sites=sites, smoke=args.smoke, epochs=args.epochs,
+        smoke=args.smoke, epochs=args.epochs,
         ckpt_dir=ckpt_dir, superbatch_k=args.superbatch_k,
         eval_every=args.eval_every, ckpt_every=args.ckpt_every,
         metrics_path=ckpt_dir + "/metrics.jsonl")
@@ -65,7 +74,7 @@ def train_tnn(args: argparse.Namespace) -> None:
     if tcfg.wave_batch % ndata:
         tcfg = dataclasses.replace(
             tcfg, wave_batch=ndata * max(tcfg.wave_batch // ndata, 1))
-    print(f"training tnn-mnist ({cfg.n_neurons:,} neurons, "
+    print(f"training {args.arch} ({cfg.n_neurons:,} neurons, "
           f"{cfg.n_synapses:,} synapses, impl={args.impl}) on {describe(mesh)}: "
           f"{tcfg.epochs} epoch(s) x {tcfg.waves_per_epoch} waves "
           f"x batch {tcfg.wave_batch}")
@@ -170,7 +179,7 @@ def main() -> None:
     args = ap.parse_args()
 
     with profiled(args.profile):
-        if args.arch == "tnn-mnist":
+        if args.arch in TNN_ARCHS:
             train_tnn(args)
         else:
             train_lm(args)

@@ -95,7 +95,7 @@ from jax.sharding import Mesh
 from repro.core.network import (
     NetworkConfig,
     classify,
-    encode_images,
+    encode,
     init_train_state,
     make_online_step,
     make_online_superbatch_step,
@@ -115,7 +115,7 @@ from repro.utils import tracing
 @dataclasses.dataclass
 class ClassifyRequest:
     uid: int
-    image: np.ndarray  # (H, W) float intensities in [0, 1]
+    image: np.ndarray  # (H, W) intensities in [0, 1], or an (L,) series
     result: Optional[int] = None  # class id, filled when served
     t_enqueue: Optional[float] = None  # perf_counter at submit()
     t_done: Optional[float] = None  # perf_counter when the wave retired
@@ -298,7 +298,7 @@ class TNNEngine:
         # batch (at most n_slots distinct shapes ever compile) so partial
         # waves pad ENCODED spikes with the shared no-op value T instead of
         # inventing a second image-level padding convention.
-        self._encode = jax.jit(lambda imgs: encode_images(imgs, self.cfg))
+        self._encode = jax.jit(lambda imgs: encode(imgs, self.cfg))
 
         def fwd(ps, x):  # (b, S, p) spikes -> (b, S, q) last-layer times
             return network_forward(x, list(ps), self.cfg)[-1]

@@ -64,7 +64,7 @@ from repro.checkpoint.checkpointer import (
 from repro.core.network import (
     NetworkConfig,
     classify,
-    encode_images,
+    encode,
     forward_all_padded,
     init_train_state,
     make_superbatch_step,
@@ -74,6 +74,7 @@ from repro.core.network import (
     refresh_vote_table,
 )
 from repro.data.mnist_like import digits
+from repro.data.series import series
 from repro.utils import tracing
 
 
@@ -108,22 +109,28 @@ class TNNTrainConfig:
 class WaveStream:
     """Deterministic wave-indexed stream of encoded spike batches.
 
-    Generates ``n`` MNIST-like digits once, center-crops them to the
-    config's field, and encodes them to (n, sites, p) int8 spike times up
-    front; ``batch_at(wave)`` slices ``wave_batch`` rows with wrap-around —
-    a pure function of the wave counter, which is what makes checkpoint
-    replay exact.
+    Generates ``n`` labelled inputs once for the config's front end —
+    MNIST-like digits center-cropped to the config's field, or series of
+    its ``series_len`` (``data/series.py``) — and encodes them to (n,
+    sites, p) uint8 spike times up front (``images`` keeps the raw
+    inputs); ``batch_at(wave)`` slices ``wave_batch`` rows with
+    wrap-around — a pure function of the wave counter, which is what makes
+    checkpoint replay exact.
     """
 
     def __init__(self, cfg: NetworkConfig, n: int, wave_batch: int,
                  seed: int = 1):
         from repro.configs.tnn_mnist import crop_field
 
-        imgs, labels = digits(n, seed=seed)
-        imgs = crop_field(imgs, cfg.layers[0].n_cols)
-        self.images = imgs
+        if cfg.series_len is not None:
+            inputs, labels = series(n, cfg.series_len, cfg.n_classes,
+                                    seed=seed)
+        else:
+            imgs, labels = digits(n, seed=seed)
+            inputs = crop_field(imgs, cfg.layers[0].n_cols)
+        self.images = inputs
         self.labels = labels
-        self.x = np.asarray(encode_images(jnp.asarray(imgs), cfg))
+        self.x = np.asarray(encode(jnp.asarray(inputs), cfg))
         self.n = n
         self.wave_batch = wave_batch
 

@@ -3,10 +3,11 @@
 Mosaic accepts or refuses a kernel at compile time, and the TPU compiler
 is installed even where no chip is attached, so these tests compile the
 fused wave, the per-layer forward and the whole train step at the
-prototype's real widths (625 sites, depth 2, batch 16) with
-``interpret=False`` — what the interpreter used by every other test cannot
-show. Nothing runs; a pass here is not a
-chip run.
+prototype's real widths (625 sites, depth 2, batch 16), and the
+time-series column's train step at its published shape (one site, 1,882
+synapses, 7 neurons, batch 128), with ``interpret=False`` — what the
+interpreter used by every other test cannot show. Nothing runs; a pass
+here is not a chip run.
 """
 import os
 import re
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import tnn_ucr
 from repro.configs.tnn_mnist import launcher_network_config
 from repro.core.network import init_train_state, make_train_step
 from repro.kernels import ops, padding, tnn_wave
@@ -108,3 +110,25 @@ def test_train_step_draws_uniforms_on_dense_lanes(one_chip, monkeypatch):
     assert draws == {f"f32[{SITES},2,{B},384]", f"f32[{SITES},2,{B},120]"}
     folded = f"f32[{SITES},2,{B},32,12]"
     assert not [shape for shape, _ in fusions if folded in shape]
+
+
+def test_series_train_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The published time-series column runs the per-layer kernels (padded
+    p 1,888 is past the fused wave's cap). At the wrappers' default
+    blocks the STDP kernel's uniforms blocks take 32 MiB of VMEM, over
+    v5e's 16 MiB scoped limit: the blocks come from the shape."""
+    monkeypatch.setattr(padding, "resolve_interpret",
+                        lambda interpret=None: bool(interpret))
+    cfg = tnn_ucr.network_config()
+    assert not padding.fused_wave_capable(cfg)
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_train_state(jax.random.PRNGKey(0), cfg)))
+    x = jax.ShapeDtypeStruct((128, 1, 1882), jnp.uint8, sharding=one_chip)
+    text = make_train_step(cfg).lower(state, x).compile().as_text()
+    kernels = re.findall(r"%(tnn_\w+)\.\d+ = \S+ custom-call\(", text)
+    assert sorted(kernels) == ["tnn_column_forward", "tnn_stdp_update"]
+    assert ops.stdp_block_b(1882, 7, block_b=128, block_p=128) == 32
+    # the prototype's layers keep the wrappers' blocks at any batch to 128
+    for p, q in ((32, 12), (12, 10)):
+        assert ops.stdp_block_b(p, q, block_b=128, block_p=128) == 128

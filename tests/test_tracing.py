@@ -10,11 +10,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import tnn_ucr
 from repro.configs.tnn_mnist import (crop_field, launcher_network_config,
                                      network_config)
 from repro.core.network import (
     classify,
     encode_images,
+    encode_series,
     init_train_state,
     make_online_step,
     make_superbatch_step,
@@ -118,17 +120,21 @@ def test_psum_scope_under_a_data_mesh():
     """, marker="PSUM_SCOPED")
 
 
-@pytest.mark.parametrize("which", ["encode", "classify"])
+@pytest.mark.parametrize("which", ["encode", "encode_series", "classify"])
 def test_off_path_layers_are_scoped(which):
     cfg = network_config(sites=SITES)
     if which == "encode":
         fn, args = (lambda im: encode_images(im, cfg)), (jnp.zeros((2, 7, 7)),)
+    elif which == "encode_series":
+        cfg = tnn_ucr.network_config(series_len=40, clusters=3, theta=40)
+        fn, args = (lambda s: encode_series(s, cfg)), (jnp.zeros((2, 40)),)
     else:
         fn = lambda z, vt: classify(z, vt, 8)                          # noqa: E731
         args = (jnp.zeros((2, SITES, 10), jnp.uint8),
                 jnp.ones((SITES, 10, 10)))
     seen, _ = _scopes_of(jax.jit(fn).lower(*args).compile().as_text())
-    assert seen == {f"tnn.{which}"}
+    assert seen == {tracing.CLASSIFY if which == "classify"
+                    else tracing.ENCODE}
 
 
 def _pallas_names(fn, *args):
